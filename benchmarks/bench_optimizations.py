@@ -379,11 +379,10 @@ _PACKED_SCRIPT = textwrap.dedent(
     import os, sys, time, json
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax
-    from repro import compat
     from repro.core import boosting
     from repro.data import get_dataset
     from repro.fl.partition import iid_partition
-    from repro.fl.sharded import sharded_adaboost_round
+    from repro.fl.sharded import federation_mesh, sharded_adaboost_round
     from repro.learners import LearnerSpec, get_learner
 
     rounds = int(sys.argv[1])
@@ -393,9 +392,9 @@ _PACKED_SCRIPT = textwrap.dedent(
     lspec = LearnerSpec("decision_tree", dspec.n_features, dspec.n_classes,
                         {"depth": 4, "n_bins": 16})
     learner = get_learner(lspec.name)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = federation_mesh(8)
     rows = []
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for name, packed in [("sharded_per_leaf_broadcast", False),
                              ("+packed_broadcast", True)]:
             rfn = jax.jit(lambda s, X, y, m: sharded_adaboost_round(
@@ -422,23 +421,22 @@ _PACKED_SCRIPT = textwrap.dedent(
 
 def _packed_broadcast_ablation(rounds: int):
     src = str(Path(__file__).resolve().parent.parent / "src")
-    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+    # the child runs on 8 fake CPU devices; pinned to the CPU because this
+    # parent has already imported JAX and may hold the accelerator
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
         p for p in [src, os.environ.get("PYTHONPATH", "")] if p
     ))
     env.pop("XLA_FLAGS", None)
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-c", _PACKED_SCRIPT, str(rounds)],
-            env=env, capture_output=True, text=True, timeout=1200,
-        )
-    except (subprocess.TimeoutExpired, OSError) as e:
-        print(f"# packed_broadcast ablation failed: {e}")
-        return []
+    proc = subprocess.run(
+        [sys.executable, "-c", _PACKED_SCRIPT, str(rounds)],
+        env=env, capture_output=True, text=True, timeout=1200,
+    )
     for line in proc.stdout.splitlines():
         if line.startswith("PACKED_JSON "):
             return json.loads(line[len("PACKED_JSON "):])
-    print(f"# packed_broadcast ablation failed:\n{proc.stderr[-2000:]}")
-    return []
+    raise RuntimeError(
+        f"packed_broadcast ablation child exited {proc.returncode}:\n{proc.stderr[-2000:]}"
+    )
 
 
 if __name__ == "__main__":

@@ -69,8 +69,9 @@ class OptimizationFlags:
         fits; with ``use_pallas`` the per-level histogram is a single
         ``tree_hist`` kernel launch whose grid folds the batch axis.
     tree_block_s / tree_block_d: sample/feature tile sizes of the
-        ``tree_hist`` kernel (TPU tuning knobs; ignored on the oracle
-        path).
+        ``tree_hist`` kernel; ``None`` (the default) derives them from
+        the shape so they are legal for the TPU and fit its VMEM
+        (``kernels/tree_hist.tiles``; ignored on the oracle path).
     """
 
     packed_serialization: bool = True
@@ -81,8 +82,8 @@ class OptimizationFlags:
     use_pallas: bool = False
     cache_predictions: bool = True
     batched_fit: bool = True
-    tree_block_s: int = 512
-    tree_block_d: int = 8
+    tree_block_s: Optional[int] = None
+    tree_block_d: Optional[int] = None
 
 
 @dataclasses.dataclass(frozen=True)

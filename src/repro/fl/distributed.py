@@ -84,19 +84,31 @@ _M_ROUND_SECONDS = obs_metrics.histogram(
 _INITIALIZED = False
 
 
+def pin_cpu() -> None:
+    """Pin this process to the CPU backend.
+
+    The multi-process runtimes (gloo collectives here, the TCP star of
+    ``fl/elastic_dist.py``) are CPU transports with one process per
+    collaborator.  An accelerator belongs to one process at a time, so
+    none of these processes may reach for it.  Must run before the
+    process's first JAX computation."""
+    jax.config.update("jax_platforms", "cpu")
+
+
 def initialize(coordinator_address: str, num_processes: int, process_id: int) -> None:
     """Join the federation's process group (idempotent).
 
-    Must run before any other JAX call in the process: it selects the
-    gloo CPU collective backend and registers with the coordinator
-    service (process 0 hosts it at ``coordinator_address``).  With
-    ``num_processes=1`` this still goes through ``jax.distributed`` so a
-    1-process run exercises the identical code path as the N-process
-    bench points.
+    Must run before any other JAX call in the process: it pins the
+    process to the CPU (:func:`pin_cpu`), selects the gloo CPU collective
+    backend and registers with the coordinator service (process 0 hosts
+    it at ``coordinator_address``).  With ``num_processes=1`` this still
+    goes through ``jax.distributed`` so a 1-process run exercises the
+    identical code path as the N-process bench points.
     """
     global _INITIALIZED
     if _INITIALIZED:
         return
+    pin_cpu()
     jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
@@ -172,9 +184,6 @@ class DistributedFederation:
         with trace.span(span_name, round=r, payload=label,
                         collective="all_gather") as sp:
             out = multihost_utils.process_allgather(x, tiled=False)
-            if self.C == 1:
-                # single-process groups skip the stacking a real gather does
-                out = jax.tree.map(lambda l: np.asarray(l)[None], out)
             nbytes = int(sum(l.nbytes for l in jax.tree.leaves(out)))
             sp.set(bytes=nbytes)
         self.comm_bytes += nbytes
@@ -188,10 +197,10 @@ class DistributedFederation:
 
         ``packed_broadcast`` ON (the §5.1 buffer-packing analogue, same
         packing as ``fl/sharded.py``): the local hypothesis pytree is
-        flattened into ONE f32 wire buffer, so the broadcast is a single
+        flattened into ONE i32 wire buffer, so the broadcast is a single
         collective per round.  OFF: one collective per leaf — the
         pre-optimisation OpenFL behaviour, kept as the ``BENCH_distributed``
-        ablation arm.  Both are lossless (i32 leaves travel bitcast), so
+        ablation arm.  Both are lossless (f32 leaves travel bitcast), so
         the ablation changes wire schedule, never results.
         """
         if self.packed_broadcast:

@@ -19,11 +19,29 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.core import scoring
 from repro.core.boosting import BoostState, Ensemble, _samme_alpha, _set_slot, _take_slot
 from repro.kernels import ops
 from repro.learners.base import LearnerSpec, WeakLearner
+
+
+def federation_mesh(n_collaborators: int) -> Mesh:
+    """The SPMD round's ``(data, model)`` mesh over every visible device:
+    one ``data`` index per collaborator, the remaining devices on
+    ``model``.  Its axes are Auto: the round and the host code around it
+    (``f1_macro`` on the batch-sharded predictions) rely on sharding
+    propagation, which Explicit axes — ``jax.make_mesh``'s default —
+    turn into errors."""
+    n_dev = len(jax.devices())
+    if n_dev % n_collaborators:
+        raise ValueError(
+            f"{n_collaborators} collaborators do not divide over {n_dev} devices; "
+            "on CPU run under XLA_FLAGS=--xla_force_host_platform_device_count=<C*m>"
+        )
+    return jax.make_mesh(
+        (n_collaborators, n_dev // n_collaborators), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
+    )
 
 
 def fl_axes(mesh: Mesh) -> Tuple[str, ...]:
@@ -31,20 +49,23 @@ def fl_axes(mesh: Mesh) -> Tuple[str, ...]:
 
 
 def _pack_leaves(tree):
-    """Flatten a (f32/i32) pytree into ONE f32 wire buffer + metadata —
+    """Flatten a (f32/i32) pytree into ONE i32 wire buffer + metadata —
     the paper's gRPC buffer-packing optimisation applied to the
     hypothesis-broadcast collective (§Perf iteration: one all-gather
-    instead of one per leaf)."""
+    instead of one per leaf).
+
+    Float leaves travel bitcast into the integer buffer.  The buffer is
+    integer because XLA:TPU lowers an all-gather to an all-reduce (a sum
+    into zeros), which is exact on integers but flushes f32 denormals —
+    and a small i32 such as a tree's feature index, bitcast to f32, is a
+    denormal."""
     leaves, treedef = jax.tree.flatten(tree)
     flats, meta, off = [], [], 0
     for l in leaves:
         fl = l.reshape(-1)
-        if fl.dtype == jnp.int32:
-            fl = jax.lax.bitcast_convert_type(fl, jnp.float32)
-            kind = "i32"
-        else:
-            fl = fl.astype(jnp.float32)
-            kind = str(l.dtype)
+        kind = str(l.dtype)
+        if fl.dtype != jnp.int32:
+            fl = jax.lax.bitcast_convert_type(fl.astype(jnp.float32), jnp.int32)
         flats.append(fl)
         meta.append((off, l.shape, kind))
         off += fl.shape[0]
@@ -60,10 +81,8 @@ def _unpack_leaves(buf, fmt, lead=()):
         for s in shape:
             n *= s
         fl = jax.lax.dynamic_slice_in_dim(buf, off, n, axis=-1)
-        if kind == "i32":
-            fl = jax.lax.bitcast_convert_type(fl, jnp.int32)
-        elif kind != "float32":
-            fl = fl.astype(kind)
+        if kind != "int32":
+            fl = jax.lax.bitcast_convert_type(fl, jnp.float32).astype(kind)
         leaves.append(fl.reshape(lead + shape))
     return jax.tree.unflatten(treedef, leaves)
 
@@ -83,7 +102,7 @@ def sharded_adaboost_round(
     """One AdaBoost.F round, collaborator-parallel over the mesh.
 
     ``packed_broadcast`` (default ON — the §5.1 buffer-packing analogue)
-    flattens the weak-hypothesis pytree into one f32 wire buffer so the
+    flattens the weak-hypothesis pytree into one i32 wire buffer so the
     broadcast is ONE all-gather per round instead of one per leaf; flip
     off for the pre-optimisation per-leaf behaviour (the
     ``+packed_broadcast`` ablation stage in bench_optimizations).
@@ -152,7 +171,7 @@ def sharded_adaboost_round(
 
     coll = P(axes) if axes else P()
     cache_args = (state.fit_cache,) if has_cache else ()
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P(), P(), coll, P(), coll, coll, coll) + (coll,) * len(cache_args),
@@ -216,7 +235,7 @@ def make_batch_predict(
         )
 
     coll = P(axes) if axes else P()
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=(P(), P(), P(), coll), out_specs=coll, check_vma=False
     )
     return jax.jit(fn)

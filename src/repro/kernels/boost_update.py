@@ -5,9 +5,10 @@
     so this is H x n work per round — the round's reduction hot-spot);
   * ``weight_update``     — w <- w * exp(alpha * mis) * mask, fused.
 
-Both stream samples through VMEM tiles; the error kernel keeps an [Hblk]
-accumulator tile resident while the sample axis (innermost grid dim)
-sweeps.
+Both stream samples through VMEM tiles; the error kernel keeps an
+[Hblk, 1] accumulator tile resident while the sample axis (innermost
+grid dim) sweeps.  Samples sit on the lane axis (``y``/``w`` as
+``[1, n]`` rows), so every block is full or (8, 128)-aligned.
 """
 from __future__ import annotations
 
@@ -17,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+_ERR_TILE_BYTES = 2 * 1024 * 1024  # one [Hblk, S] i32 prediction tile
+
 
 def _err_kernel(preds_ref, y_ref, w_ref, out_ref):
     si = pl.program_id(1)
@@ -25,8 +28,9 @@ def _err_kernel(preds_ref, y_ref, w_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    mis = (preds_ref[...] != y_ref[...][None, :]).astype(jnp.float32)  # [Hblk, S]
-    out_ref[...] += mis @ w_ref[...].astype(jnp.float32)  # [Hblk]
+    mis = (preds_ref[...] != y_ref[...]).astype(jnp.float32)  # [Hblk, S]
+    # a lane reduction on the VPU, not an MXU matvec: f32-exact products
+    out_ref[...] += jnp.sum(mis * w_ref[...].astype(jnp.float32), axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_h", "block_s", "interpret"))
@@ -35,32 +39,36 @@ def weighted_errors(
     y: jax.Array,  # [n] i32
     w: jax.Array,  # [n] f32
     *,
-    block_h: int = 8,
-    block_s: int = 2048,
+    block_h: int | None = None,
+    block_s: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
+    """``block_h``/``block_s`` override the shape-derived tiles (all
+    hypotheses up to 256 in one block; samples in multiples of 128)."""
     H, n = preds.shape
-    block_h = min(block_h, H)
-    block_s = min(block_s, n)
+    block_h = min(block_h or 256, H)
+    rows = -(-block_h // 8) * 8  # hypotheses padded to whole sublane tiles
+    auto_s = max(128, _ERR_TILE_BYTES // (4 * rows) // 128 * 128)
+    block_s = min(block_s or auto_s, n)
     nh, ns = -(-H // block_h), -(-n // block_s)
     hp, np_ = nh * block_h, ns * block_s
     # Padded samples: w = 0 (no contribution). Padded hypotheses sliced off.
     preds = jnp.pad(preds, ((0, hp - H), (0, np_ - n)))
-    y = jnp.pad(y, (0, np_ - n), constant_values=-1)
-    w = jnp.pad(w, (0, np_ - n))
+    y = jnp.pad(y, (0, np_ - n), constant_values=-1)[None, :]
+    w = jnp.pad(w, (0, np_ - n))[None, :]
     out = pl.pallas_call(
         _err_kernel,
         grid=(nh, ns),
         in_specs=[
             pl.BlockSpec((block_h, block_s), lambda hi, si: (hi, si)),
-            pl.BlockSpec((block_s,), lambda hi, si: (si,)),
-            pl.BlockSpec((block_s,), lambda hi, si: (si,)),
+            pl.BlockSpec((1, block_s), lambda hi, si: (0, si)),
+            pl.BlockSpec((1, block_s), lambda hi, si: (0, si)),
         ],
-        out_specs=pl.BlockSpec((block_h,), lambda hi, si: (hi,)),
-        out_shape=jax.ShapeDtypeStruct((hp,), jnp.float32),
+        out_specs=pl.BlockSpec((block_h, 1), lambda hi, si: (hi, 0)),
+        out_shape=jax.ShapeDtypeStruct((hp, 1), jnp.float32),
         interpret=interpret,
     )(preds, y, w)
-    return out[:H]
+    return out[:H, 0]
 
 
 def _upd_kernel(w_ref, mis_ref, mask_ref, alpha_ref, out_ref):

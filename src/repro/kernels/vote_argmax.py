@@ -8,9 +8,12 @@ inference side).  At serve time this is the only reduction between the
 per-member predicts and the response, so it pairs with the
 ``boost_update`` kernels the same way inference pairs with training.
 
-The member axis (innermost grid dim) sweeps while an [Nblk, K] vote
+The member axis (innermost grid dim) sweeps while a ``[K, Nblk]`` vote
 accumulator stays resident; the final member block writes the argmax.
-Padded members carry alpha == 0 and therefore vote with weight zero.
+Samples sit on the lane axis and members on sublanes, with alpha as a
+``[T, 1]`` column, so the class loop builds only 2-D ``[Tblk, Nblk]``
+tiles.  Padded members carry alpha == 0 and therefore vote with weight
+zero.
 """
 from __future__ import annotations
 
@@ -19,9 +22,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _vote_kernel(preds_ref, alpha_ref, votes_ref, out_ref, *, n_classes):
+def _vote_kernel(preds_ref, alpha_ref, out_ref, votes_ref, *, n_classes):
     ti = pl.program_id(1)
 
     @pl.when(ti == 0)
@@ -29,14 +33,22 @@ def _vote_kernel(preds_ref, alpha_ref, votes_ref, out_ref, *, n_classes):
         votes_ref[...] = jnp.zeros_like(votes_ref)
 
     p = preds_ref[...]  # [Tblk, Nblk] i32
-    a = alpha_ref[...].astype(jnp.float32)  # [Tblk]
-    k_ids = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n_classes), 2)
-    onehot = (p[:, :, None] == k_ids).astype(jnp.float32)  # [Tblk, Nblk, K]
-    votes_ref[...] += jnp.sum(a[:, None, None] * onehot, axis=0)  # [Nblk, K]
+    a = alpha_ref[...].astype(jnp.float32)  # [Tblk, 1]
+    for k in range(n_classes):
+        votes_ref[pl.ds(k, 1), :] += jnp.sum(
+            jnp.where(p == k, a, 0.0), axis=0, keepdims=True
+        )
 
     @pl.when(ti == pl.num_programs(1) - 1)
     def _finish():
-        out_ref[...] = jnp.argmax(votes_ref[...], axis=-1).astype(jnp.int32)
+        # first maximum wins, as in jnp.argmax
+        best = votes_ref[pl.ds(0, 1), :]
+        arg = jnp.zeros(best.shape, jnp.int32)
+        for k in range(1, n_classes):
+            v = votes_ref[pl.ds(k, 1), :]
+            arg = jnp.where(v > best, k, arg)
+            best = jnp.maximum(v, best)
+        out_ref[...] = arg
 
 
 @functools.partial(
@@ -47,7 +59,7 @@ def vote_argmax(
     alpha: jax.Array,  # [T] f32 — member weights (unused slots = 0)
     *,
     n_classes: int,
-    block_t: int = 32,
+    block_t: int = 256,
     block_n: int = 1024,
     interpret: bool = False,
 ) -> jax.Array:
@@ -57,24 +69,19 @@ def vote_argmax(
     nt, nn = -(-T // block_t), -(-n // block_n)
     tp, np_ = nt * block_t, nn * block_n
     # Padded members: alpha = 0 (vote with zero weight). Padded samples
-    # produce garbage rows that are sliced off below.
+    # produce garbage columns that are sliced off below.
     preds = jnp.pad(preds, ((0, tp - T), (0, np_ - n)))
-    alpha = jnp.pad(alpha, (0, tp - T))
-    _, out = pl.pallas_call(
+    alpha = jnp.pad(alpha, (0, tp - T))[:, None]
+    out = pl.pallas_call(
         functools.partial(_vote_kernel, n_classes=n_classes),
         grid=(nn, nt),
         in_specs=[
             pl.BlockSpec((block_t, block_n), lambda ni, ti: (ti, ni)),
-            pl.BlockSpec((block_t,), lambda ni, ti: (ti,)),
+            pl.BlockSpec((block_t, 1), lambda ni, ti: (ti, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((block_n, n_classes), lambda ni, ti: (ni, 0)),
-            pl.BlockSpec((block_n,), lambda ni, ti: (ni,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((np_, n_classes), jnp.float32),
-            jax.ShapeDtypeStruct((np_,), jnp.int32),
-        ],
+        out_specs=pl.BlockSpec((1, block_n), lambda ni, ti: (0, ni)),
+        out_shape=jax.ShapeDtypeStruct((1, np_), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((n_classes, block_n), jnp.float32)],
         interpret=interpret,
     )(preds, alpha)
-    return out[:n]
+    return out[0, :n]

@@ -25,7 +25,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro import compat, roofline  # noqa: E402
+from repro import roofline  # noqa: E402
 from repro.configs import INPUT_SHAPES, all_archs, get_arch  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.models import model as M  # noqa: E402
@@ -87,7 +87,7 @@ def _compile(cfg, shape, mesh, policy="baseline", zero1=False, accum=1):
     in_specs = M.input_specs(cfg, shape)
     ispecs = shardings.input_spec_tree(cfg, shape, in_specs, mesh)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt_shapes = jax.eval_shape(init_adamw, shapes)
             opt_pspecs = shardings.param_specs(
@@ -351,7 +351,7 @@ def run_fl_round(mesh_kind: str, out_dir: Path, force=False, packed=False):
     m = sds((C, n), jnp.float32)
 
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn = jax.jit(
             lambda s, X, y, m: sharded_adaboost_round(
                 learner, lspec, mesh, s, X, y, m, packed_broadcast=packed

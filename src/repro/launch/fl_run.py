@@ -25,13 +25,13 @@ import time
 
 import jax
 
-from repro import compat
 from repro.core.hetero import HeterogeneousSpec
 from repro.core.metrics import f1_macro
 from repro.core.plan import OptimizationFlags, adaboost_plan, bagging_plan, fedavg_plan
 from repro.data import get_dataset
 from repro.fl.federation import Federation
 from repro.fl.partition import partition
+from repro.launch import jax_cache
 from repro.learners import LearnerSpec
 from repro.obs import metrics as obs_metrics, trace
 
@@ -63,8 +63,10 @@ def main(argv=None):
     ap.add_argument("--eval-every", type=int, default=5)
     ap.add_argument("--faithful", action="store_true")
     ap.add_argument("--use-pallas", action="store_true",
-                    help="route step-3/4 scoring through the Pallas kernels "
-                         "(TPU; interpret mode elsewhere)")
+                    help="route the tree-fit histogram and the step-3/4 "
+                         "scoring through the Pallas kernels: compiled on a "
+                         "TPU, interpret mode on the CPU (slow at large "
+                         "shapes)")
     ap.add_argument("--sharded", action="store_true")
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="record per-round phase spans (fit/score/aggregate/"
@@ -134,9 +136,11 @@ def main(argv=None):
                     help="collaborator PID offline for rounds [OFF, REJOIN) "
                          "then rejoins (repeatable)")
     args = ap.parse_args(argv)
+    jax_cache.use_persistent_cache()
     if args.distributed:
-        # must precede every other JAX call in the process: picks the gloo
-        # CPU collective backend and joins the coordinator's process group
+        # must precede every other JAX call in the process: pins it to the
+        # CPU, picks the gloo CPU collective backend and joins the
+        # coordinator's process group (the elastic TCP star only pins)
         if args.faithful or args.sharded or args.learners:
             ap.error("--distributed replaces --faithful/--sharded and is "
                      "homogeneous-only (no --learners)")
@@ -146,9 +150,11 @@ def main(argv=None):
             ap.error(f"--distributed is process-per-collaborator: "
                      f"--collaborators {args.collaborators} != "
                      f"--num-processes {args.num_processes}")
-        if not args.elastic:
-            from repro.fl import distributed as _dist
+        from repro.fl import distributed as _dist
 
+        if args.elastic:
+            _dist.pin_cpu()
+        else:
             _dist.initialize(args.coordinator, args.num_processes, args.process_id)
     if args.trace:
         trace.enable()
@@ -348,21 +354,19 @@ def _run_sharded(args, lspec, Xs, ys, masks, Xte, yte, key):
     import jax.numpy as jnp
 
     from repro.core import boosting
-    from repro.fl.sharded import sharded_adaboost_round, sharded_strong_predict
+    from repro.fl.sharded import (
+        federation_mesh, sharded_adaboost_round, sharded_strong_predict,
+    )
     from repro.learners import get_learner
 
     n_dev = len(jax.devices())
     C = Xs.shape[0]
-    assert n_dev % 1 == 0 and C <= n_dev, (
-        f"--sharded needs >= {C} devices (have {n_dev}); "
-        "run under XLA_FLAGS=--xla_force_host_platform_device_count=<C*m>"
-    )
-    mesh = jax.make_mesh((C, n_dev // C), ("data", "model"))
+    mesh = federation_mesh(C)
     learner = get_learner(lspec.name)
     # X=Xs: shard-static fit precomputation (BinnedDataset for trees) is
     # built once here and consumed inside the shard_map round.
     state = boosting.init_boost_state(learner, lspec, args.rounds, masks, key, X=Xs)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         rfn = jax.jit(
             lambda s, X, y, m: sharded_adaboost_round(
                 learner, lspec, mesh, s, X, y, m, use_pallas=args.use_pallas

@@ -128,7 +128,6 @@ def spawn(
     coord = f"127.0.0.1:{free_port()}"
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)  # fake-device counts break 1-device-per-process
-    env.setdefault("JAX_PLATFORMS", "cpu")
 
     procs, logs = [], []
     for i in range(num_processes):

@@ -43,6 +43,7 @@ from repro.core.hetero import HeterogeneousSpec
 from repro.core.metrics import f1_macro
 from repro.data import get_dataset
 from repro.fl.partition import iid_partition
+from repro.launch import jax_cache
 from repro.launch.fl_run import _finish_obs, default_hparams
 from repro.learners import LearnerSpec, get_learner
 from repro.obs import trace
@@ -259,6 +260,7 @@ def main(argv=None):
                          "families) in Prometheus text exposition format")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    jax_cache.use_persistent_cache()
     if args.trace:
         trace.enable()
 

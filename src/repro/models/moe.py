@@ -18,7 +18,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ArchConfig
 from repro.models.layers import make_param, pdtype
 from repro.models.shardings import maybe_gather_weight as _mg
@@ -72,7 +71,7 @@ def apply_moe(cfg: ArchConfig, p: Dict, x: jax.Array) -> Tuple[jax.Array, jax.Ar
     N = B * S
     G = DISPATCH_GROUPS if (DISPATCH_GROUPS > 1 and N % DISPATCH_GROUPS == 0) else 1
     if G > 1:
-        mesh = compat.get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         dp = tuple(a for a in ("pod", "data") if mesh is not None and a in mesh.shape)
         import numpy as _np
         dp_n = int(_np.prod([mesh.shape[a] for a in dp])) if dp else 1
@@ -86,7 +85,7 @@ def apply_moe(cfg: ArchConfig, p: Dict, x: jax.Array) -> Tuple[jax.Array, jax.Ar
                 # XLA-CPU AllReducePromotion bug — see EXPERIMENTS.md §Perf).
                 return out, aux[None]
 
-            fn = compat.shard_map(
+            fn = jax.shard_map(
                 local,
                 mesh=mesh,
                 in_specs=(_P(dp, None, None),),

@@ -18,7 +18,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ArchConfig, InputShape
 
 # logical axis -> candidate mesh axis (in priority order per-leaf)
@@ -113,7 +112,7 @@ def constrain_group_dim(x):
     reshapes from [B, S, ...] can silently drop the batch sharding, after
     which XLA replicates the whole MoE dispatch (observed as 51 GB/layer
     hidden-state all-gathers on grok).  No-op outside a mesh context."""
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return x
     dp = tuple(a for a in ("pod", "data") if a in mesh.shape)
@@ -126,7 +125,7 @@ def constrain_microbatch(x):
     """Pin dim 1 of an [accum, B/accum, ...] microbatch stack to the
     data-parallel axes (the reshape from [B, ...] can drop the batch
     sharding, replicating every microbatch's activations)."""
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty or x.ndim < 2:
         return x
     dp = tuple(a for a in ("pod", "data") if a in mesh.shape)
@@ -139,7 +138,7 @@ def maybe_gather_weight(w, axes: Tuple[Optional[str], ...]):
     """Apply a model-only sharding constraint to a weight (strips 'data')."""
     if not FSDP_WEIGHT_GATHER:
         return w
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty or "model" not in mesh.shape:
         return w
     model_n = mesh.shape["model"]

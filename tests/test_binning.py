@@ -270,7 +270,9 @@ def test_local_fits_dispatches_to_fit_batched():
 def test_optimization_flags_expose_tree_tiling():
     flags = OptimizationFlags()
     assert flags.batched_fit is True
-    assert flags.tree_block_s == 512 and flags.tree_block_d == 8
+    # tiles follow the shape unless set (kernels/tree_hist.tiles)
+    assert flags.tree_block_s is None and flags.tree_block_d is None
+    flags = dataclasses.replace(flags, tree_block_s=512, tree_block_d=8)
     # a round accepts the tiling knobs on the oracle path (no-ops there)
     key = jax.random.PRNGKey(15)
     Xs, ys, ws = _shards(key)

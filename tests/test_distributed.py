@@ -177,7 +177,7 @@ def test_multiprocess_equals_fused_bitforbit(tmp_path):
 
 def test_pack_unpack_roundtrip():
     """The packed one-buffer wire format is lossless for f32 + i32 pytrees
-    (i32 leaves travel bitcast through the f32 buffer)."""
+    (f32 leaves travel bitcast through the i32 buffer)."""
     import jax
     import jax.numpy as jnp
 
@@ -193,10 +193,10 @@ def test_pack_unpack_roundtrip():
     for k in tree:
         assert out[k].dtype == tree[k].dtype, k
         np.testing.assert_array_equal(np.asarray(out[k]), np.asarray(tree[k]), err_msg=k)
-    # gathered form: a stacked [P, L] buffer unpacks with a lead dim
-    # (stack only — arithmetic on the buffer would flush the denormal
-    # bit-patterns i32 leaves travel as; the wire never does arithmetic)
-    stacked = jnp.stack([buf, buf])
+    # gathered form: a stacked [P, L] buffer unpacks with a lead dim; an
+    # all-gather lowered to a sum into zeros (XLA:TPU) leaves it exact
+    assert buf.dtype == jnp.int32
+    stacked = jnp.stack([buf, buf]) + jnp.zeros((2,) + buf.shape, buf.dtype)
     out2 = _unpack_leaves(stacked, fmt, lead=(2,))
     for k in tree:
         assert out2[k].shape == (2,) + tree[k].shape, k

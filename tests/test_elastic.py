@@ -97,9 +97,10 @@ def test_masked_helpers_all_ones_identity():
     assert float(scoring.participation_denom(w, part)) == 1.0
     mis = jnp.asarray(rng.integers(0, 2, (5, 11)), jnp.float32)
     mask = jnp.ones((5, 11), jnp.float32)
-    np.testing.assert_array_equal(
+    np.testing.assert_allclose(
         np.asarray(scoring.masked_update_weights(w, mis, mask, part, 0.7)),
         np.asarray(scoring.update_weights(w, mis, mask, 0.7)),
+        rtol=scoring.CROSS_PATH_RTOL, atol=0,
     )
 
 

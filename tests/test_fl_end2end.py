@@ -114,3 +114,25 @@ def test_token_pipeline_is_learnable_and_deterministic():
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))  # same seed
     assert a.shape == (4, 33)
     assert int(a.max()) < 128 and int(a.min()) >= 0
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env-set", "env-unset"])
+def test_persistent_compile_cache_placement(monkeypatch, tmp_path, from_env):
+    """Entry points keep JAX's compile cache where JAX_COMPILATION_CACHE_DIR
+    says (the helper sets nothing), else in ``.jax_cache/`` at the repo root."""
+    from pathlib import Path
+
+    from repro.launch import jax_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        jax_cache.use_persistent_cache()
+        repo_cache = Path(__file__).resolve().parent.parent / ".jax_cache"
+        want = before if from_env else str(repo_cache)
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

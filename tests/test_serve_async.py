@@ -125,7 +125,6 @@ def test_result_timeout_and_unknown_rid_raise():
 
 
 def test_engine_config_mesh_backend_matches_local():
-    from repro import compat
     from repro.launch.mesh import make_host_mesh
 
     learner, spec, ens, X = _small_ensemble("decision_tree", jax.random.PRNGKey(40))
@@ -137,7 +136,7 @@ def test_engine_config_mesh_backend_matches_local():
     with pytest.raises(ValueError, match="inside the EngineConfig"):
         ServeEngine(learner, spec, ens, batch_size=64,
                     config=EngineConfig(batch_size=64, mesh=mesh))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         eng = ServeEngine(
             learner, spec, ens, config=EngineConfig(batch_size=64, mesh=mesh)
         )

@@ -16,15 +16,15 @@ SCRIPT = textwrap.dedent(
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
-    from repro import compat
     from repro.core import boosting
     from repro.core.metrics import f1_macro
-    from repro.fl.sharded import sharded_adaboost_round, sharded_strong_predict
+    from repro.core.scoring import CROSS_PATH_RTOL
+    from repro.fl.sharded import federation_mesh, sharded_adaboost_round, sharded_strong_predict
     from repro.learners import LearnerSpec, get_learner
     from repro.data import get_dataset
     from repro.fl.partition import iid_partition
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = federation_mesh(4)  # (data=4, model=2) over the 8 devices
     key = jax.random.PRNGKey(0)
     spec_d, (Xtr, ytr, Xte, yte) = get_dataset("vehicle", key)
     Xs, ys, masks = iid_partition(Xtr, ytr, 4, jax.random.PRNGKey(1))
@@ -33,28 +33,33 @@ SCRIPT = textwrap.dedent(
     T = 6
     # X=Xs: both paths carry the shard-static BinnedDataset fit cache —
     # the SPMD round consumes it through the shard_map boundary.
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = boosting.init_boost_state(learner, lspec, T, masks, jax.random.PRNGKey(2), X=Xs)
         assert state.fit_cache is not None
         rfn = jax.jit(lambda s, X, y, m: sharded_adaboost_round(learner, lspec, mesh, s, X, y, m))
+        chosen = []
         for _ in range(T):
             state, metrics = rfn(state, Xs, ys, masks)
+            chosen.append(int(metrics["chosen"]))
         n = Xte.shape[0] - Xte.shape[0] % 4
         pred = sharded_strong_predict(learner, lspec, mesh, state.ensemble, Xte[:n])
     f1_sharded = float(f1_macro(yte[:n], pred, lspec.n_classes))
 
     state2 = boosting.init_boost_state(learner, lspec, T, masks, jax.random.PRNGKey(2), X=Xs)
     host_fn = jax.jit(lambda s, X, y, m: boosting.adaboost_f_round(learner, lspec, s, X, y, m))
+    chosen2 = []
     for _ in range(T):
-        state2, _ = host_fn(state2, Xs, ys, masks)
+        state2, m2 = host_fn(state2, Xs, ys, masks)
+        chosen2.append(int(m2["chosen"]))
     pred2 = boosting.strong_predict(learner, lspec, state2.ensemble, Xte[:n])
     f1_host = float(f1_macro(yte[:n], pred2, lspec.n_classes))
 
+    # the cross-path contract: same choices, weights and alphas within
+    # CROSS_PATH_RTOL (protocol equivalence, not just outcome)
+    assert chosen == chosen2, (chosen, chosen2)
     assert abs(f1_sharded - f1_host) < 1e-6, (f1_sharded, f1_host)
-    # weights identical too (protocol equivalence, not just outcome)
-    np.testing.assert_allclose(
-        np.asarray(state.weights), np.asarray(state2.weights), rtol=1e-4, atol=1e-9
-    )
+    for a, b in [(state.weights, state2.weights), (state.ensemble.alpha, state2.ensemble.alpha)]:
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=CROSS_PATH_RTOL, atol=0)
 
     # serving: ONE engine spans the mesh — EngineConfig(mesh=...) routes
     # every static batch through fl/sharded.make_batch_predict (batch
@@ -62,7 +67,7 @@ SCRIPT = textwrap.dedent(
     from repro.serve import EngineConfig, ServeEngine
     Xte_n = np.asarray(Xte[:n])
     want_serve = ServeEngine(learner, lspec, state.ensemble, batch_size=64).predict(Xte_n)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         mesh_eng = ServeEngine(
             learner, lspec, state.ensemble, config=EngineConfig(batch_size=64, mesh=mesh)
         )
