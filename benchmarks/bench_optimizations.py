@@ -294,10 +294,10 @@ def _obs_overhead_ablation(Xs, ys, masks, lspec, key, rounds, repeats):
                   committed ``fused_fit+binned_batched`` baseline
                   (``BENCH_optimizations_fig3.json``), asserted in the
                   row's ``within_5pct_of_committed``;
-      obs_traced  what ``--trace`` costs: each stage jits separately and
-                  blocks on its carry so fit/score/aggregate become real
-                  host-visible phases — the price of phase attribution,
-                  NOT paid unless tracing is enabled.
+      obs_traced  what ``--trace`` costs: the same fused round, each
+                  call inside an enabled ``round`` span (a buffered
+                  event plus a profiler annotation); fit/score/aggregate
+                  are named scopes inside the program and cost nothing.
     """
     import jax as _jax
 
@@ -313,10 +313,6 @@ def _obs_overhead_ablation(Xs, ys, masks, lspec, key, rounds, repeats):
             learner, lspec, s, Xs, ys, masks, batched_fit=True
         )
     )
-    staged = [
-        (n, _jax.jit(f))
-        for n, f in boosting.adaboost_f_stages(learner, lspec, batched_fit=True)
-    ]
 
     def run_off():
         s = state
@@ -326,12 +322,9 @@ def _obs_overhead_ablation(Xs, ys, masks, lspec, key, rounds, repeats):
 
     def run_traced():
         s = state
-        for _ in range(rounds):
-            carry = {}
-            for n, sfn in staged:
-                with trace.span("round." + n):
-                    s, carry = sfn(s, carry, Xs, ys, masks)
-                    _jax.block_until_ready(carry)
+        for r in range(rounds):
+            with trace.span("round", round=r):
+                s, _m = rfn(s)
         _jax.block_until_ready(s.weights)
 
     committed = None

@@ -5,8 +5,9 @@ Validates, without any third-party tooling:
   * a ``--trace`` file is well-formed Chrome trace JSON (the shape
     Perfetto / chrome://tracing load: complete "X" events with
     microsecond ts/dur and span_id/parent_id args) and — for an fl_run
-    trace — that every ``round`` span decomposes into the per-phase
-    children the tentpole promises (fit/score/aggregate at minimum);
+    trace — that the ``round`` spans hold the host-phase children the
+    caller names (the fused path: eval/publish; the multi-process path:
+    fit/broadcast/score/aggregate);
   * a ``--metrics-out`` dump parses as Prometheus text exposition
     (HELP/TYPE headers, numeric samples, cumulative histogram buckets)
     and covers the expected metric families of every serving subsystem.
@@ -14,7 +15,7 @@ Validates, without any third-party tooling:
 Usage::
 
     python scripts/check_obs.py --trace /tmp/fl_trace.json \
-        --round-children round.fit,round.score,round.aggregate
+        --round-children round.eval,round.publish
     python scripts/check_obs.py --metrics /tmp/serve_metrics.prom \
         --families mafl_engine_,mafl_scheduler_,mafl_registry_
 

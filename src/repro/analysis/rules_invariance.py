@@ -75,8 +75,8 @@ def check_batch_matvec(project: Project):
 @rule(
     "stage-barrier",
     "a fused stage-composition loop without an optimization_barrier (or "
-    "per-stage jit + block) lets XLA fuse reductions across what the "
-    "distributed runtime runs as a network collective",
+    "a block on each stage's own jit) lets XLA fuse reductions across what "
+    "the distributed runtime runs as a network collective",
 )
 def check_stage_barrier(project: Project):
     for mod in project.modules:

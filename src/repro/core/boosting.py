@@ -184,15 +184,16 @@ def run_stages(stages, state: BoostState, X, y, mask):
     Every round below is built from (name, fn) stages with the uniform
     signature ``fn(state, carry, X, y, mask) -> (state, carry)`` — the
     final stage leaves the round metrics in ``carry["metrics"]``.  The
-    fused round functions jit THIS composition, while the observability
-    layer jits each stage separately to time fit / score / aggregate as
-    real host-visible phases (``fl/federation.py`` under ``--trace``).
+    fused round functions jit THIS composition.  Each stage runs under
+    ``jax.named_scope(<name>)``, so its ops carry ``fit`` / ``score`` /
+    ``aggregate`` in their metadata and a profiler trace of the one
+    fused program attributes device time to the round's steps.
 
     An ``optimization_barrier`` seals each stage boundary so XLA cannot
     fuse reductions ACROSS stages (e.g. folding the score stage's error
     matrix straight into the aggregate stage's eps sum, which reassociates
     the reduction).  This pins one canonical numeric result for a round:
-    the one fused jit, the per-stage traced jits, and the per-collaborator
+    the one fused jit, each stage jitted alone, and the per-collaborator
     distributed runtime (``fl/distributed.py`` — where the stage boundary
     is a real network collective and fusing across it is impossible) are
     all bit-for-bit identical, which is what the multi-process equivalence
@@ -200,8 +201,9 @@ def run_stages(stages, state: BoostState, X, y, mask):
     stage's internals compile exactly as before.
     """
     carry: Dict[str, Any] = {}
-    for _, fn in stages:
-        state, carry = fn(state, carry, X, y, mask)
+    for name, fn in stages:
+        with jax.named_scope(name):
+            state, carry = fn(state, carry, X, y, mask)
         state, carry = jax.lax.optimization_barrier((state, carry))
     return state, carry["metrics"]
 
@@ -569,14 +571,4 @@ ROUND_FNS: Dict[str, Callable] = {
     "adaboost_f": adaboost_f_round,
     "distboost_f": distboost_f_round,
     "bagging": bagging_round,
-}
-
-# Stage factories for the traced path (fl/federation.py under --trace):
-# same computation as ROUND_FNS, but each named stage can be jitted and
-# timed on its own.  PreWeak.F is absent — its stage factory needs the
-# hypothesis space, so the federation calls preweak_f_stages directly.
-ROUND_STAGES: Dict[str, Callable] = {
-    "adaboost_f": adaboost_f_stages,
-    "distboost_f": distboost_f_stages,
-    "bagging": bagging_stages,
 }

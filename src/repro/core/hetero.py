@@ -629,14 +629,6 @@ HETERO_ROUND_FNS = {
     "bagging": hetero_bagging_round,
 }
 
-# Traced-path stage factories (see boosting.ROUND_STAGES); PreWeak.F is
-# handled by the federation calling hetero_preweak_f_stages directly.
-HETERO_ROUND_STAGES = {
-    "adaboost_f": hetero_adaboost_f_stages,
-    "distboost_f": hetero_distboost_f_stages,
-    "bagging": hetero_bagging_stages,
-}
-
 
 # ---------------------------------------------------------------------------
 # Evaluation — votes commute, so the mixture is a sum of group tallies

@@ -225,16 +225,18 @@ class ParticipationPolicy:
 
 def run_elastic_stages(stages, state: BoostState, X, y, mask, part):
     """:func:`boosting.run_stages` with the responder mask threaded
-    through; the same ``optimization_barrier`` seals every stage
-    boundary so the masked round compiles to the same per-stage numeric
-    programs as the lockstep round (the all-ones equivalence contract).
+    through; each stage runs under its ``jax.named_scope`` and the same
+    ``optimization_barrier`` seals every stage boundary so the masked
+    round compiles to the same per-stage numeric programs as the
+    lockstep round (the all-ones equivalence contract).
 
     Returns ``(state, metrics, round_hyps)`` — ``round_hyps`` is the
     ``[C, ...]`` fit output for algorithms whose late merges need it
     (adaboost_f / bagging), else ``None``."""
     carry: Dict[str, Any] = {}
-    for _, fn in stages:
-        state, carry = fn(state, carry, X, y, mask, part)
+    for name, fn in stages:
+        with jax.named_scope(name):
+            state, carry = fn(state, carry, X, y, mask, part)
         state, carry = jax.lax.optimization_barrier((state, carry))
     return state, carry["metrics"], carry.get("hyps")
 

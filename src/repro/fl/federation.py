@@ -313,29 +313,20 @@ class Federation:
 
     def _fused_loop(
         self, rounds: int, eval_every: int, state, Xs, ys, masks,
-        round_fn, staged, evaluate, per_round_comm: int,
+        round_fn, evaluate, per_round_comm: int,
         publish_every, publish_dir, on_checkpoint,
     ) -> List[Dict[str, float]]:
         """The round loop shared by both fused paths.
 
-        ``staged`` is the traced-mode alternative to ``round_fn``: the
-        round's named stages, each jitted separately so fit/score/
-        aggregate are real host-visible phases (``jax.block_until_ready``
-        per stage).  It is only built when tracing is enabled — disabled
-        runs execute the identical single jitted ``round_fn`` as before.
+        Traced or not, every round is one call of the jitted ``round_fn``.
+        The host spans are ``round``, ``round.eval`` and
+        ``round.publish``; fit / score / aggregate are named scopes inside
+        the program (``boosting.run_stages``), seen in a profiler trace.
         """
         self._row_marker = (time.perf_counter(), self.comm_bytes, 0)
         for r in range(rounds):
             with trace.span("round", round=r, algorithm=self.plan.algorithm):
-                if staged is not None:
-                    carry: Dict[str, Any] = {}
-                    for name, sfn in staged:
-                        with trace.span("round." + name, round=r):
-                            state, carry = sfn(state, carry, Xs, ys, masks)
-                            jax.block_until_ready(carry)
-                    metrics = carry["metrics"]
-                else:
-                    state, metrics = round_fn(state, Xs, ys, masks)
+                state, metrics = round_fn(state, Xs, ys, masks)
                 self._account_comm(per_round_comm)
                 _M_ROUNDS.inc()
                 if (r + 1) % eval_every == 0 or r == rounds - 1:
@@ -370,8 +361,6 @@ class Federation:
         masks = jnp.stack([c.mask for c in self.collaborators])
         opt = self.plan.optimizations
         up = opt.use_pallas
-        traced = trace.TRACER.enabled
-        stages = None
         committee = self.n_collaborators if self.plan.algorithm == "distboost_f" else None
         state = boosting.init_boost_state(
             self.learner, self.spec, rounds, masks, self.key,
@@ -394,19 +383,12 @@ class Federation:
                             self.learner, self.spec, hs, X
                         )
                     )(hyp_space, Xs)
-                if traced:
-                    jax.block_until_ready(hyp_space)
             round_fn = jax.jit(
                 lambda s, X, y, m: boosting.preweak_f_round(
                     self.learner, self.spec, s, hyp_space, X, y, m,
                     pred_cache=cache, use_pallas=up,
                 )
             )
-            if traced:
-                stages = boosting.preweak_f_stages(
-                    self.learner, self.spec, hyp_space,
-                    pred_cache=cache, use_pallas=up,
-                )
             setup_bytes, per_round = self._fused_comm_model(state, setup_tree=hyp_space)
             self._account_comm(setup_bytes)
         else:
@@ -418,25 +400,18 @@ class Federation:
                     block_s=opt.tree_block_s, block_d=opt.tree_block_d,
                 )
             )
-            if traced:
-                stages = boosting.ROUND_STAGES[self.plan.algorithm](
-                    self.learner, self.spec, use_pallas=up,
-                    batched_fit=opt.batched_fit,
-                    block_s=opt.tree_block_s, block_d=opt.tree_block_d,
-                )
             _, per_round = self._fused_comm_model(state)
-        staged = [(n, jax.jit(f)) for n, f in stages] if stages is not None else None
         committee_pred = self.plan.algorithm == "distboost_f"
         if opt.cache_predictions:
             # incremental eval: running vote tally; each eval adds only the
             # members appended since the previous one
             tally = scoring.init_tally(self.X_test.shape[0], self.spec.n_classes)
-            tally_fn = jax.jit(
+            tally_fn = jax.jit(jax.named_scope("eval")(
                 lambda ens, tl: scoring.tally_new_votes(
                     self.learner, self.spec, ens, tl, self.X_test,
                     committee=committee_pred,
                 )
-            )
+            ))
 
             def evaluate(state):
                 nonlocal tally
@@ -445,18 +420,18 @@ class Federation:
                 return f1_macro(self.y_test, pred, self.spec.n_classes)
 
         else:
-            predict = jax.jit(
+            predict = jax.jit(jax.named_scope("eval")(
                 lambda ens, X: boosting.strong_predict(
                     self.learner, self.spec, ens, X, committee=committee_pred
                 )
-            )
+            ))
 
             def evaluate(state):
                 pred = predict(state.ensemble, self.X_test)
                 return f1_macro(self.y_test, pred, self.spec.n_classes)
 
         return self._fused_loop(
-            rounds, eval_every, state, Xs, ys, masks, round_fn, staged,
+            rounds, eval_every, state, Xs, ys, masks, round_fn,
             evaluate, per_round, publish_every, publish_dir, on_checkpoint,
         )
 
@@ -477,8 +452,6 @@ class Federation:
         masks = jnp.stack([c.mask for c in self.collaborators])
         opt = self.plan.optimizations
         up = opt.use_pallas
-        traced = trace.TRACER.enabled
-        stages = None
         committee = self.plan.algorithm == "distboost_f"
         state = hetero.init_hetero_boost_state(
             hspec, rounds, masks, self.key, committee=committee, X=Xs,
@@ -496,17 +469,11 @@ class Federation:
                     cache = jax.jit(
                         lambda sp, X: hetero.hetero_preweak_f_predictions(hspec, sp, X)
                     )(spaces, Xs)
-                if traced:
-                    jax.block_until_ready(spaces)
             round_fn = jax.jit(
                 lambda s, X, y, m: hetero.hetero_preweak_f_round(
                     hspec, s, spaces, X, y, m, pred_cache=cache, use_pallas=up,
                 )
             )
-            if traced:
-                stages = hetero.hetero_preweak_f_stages(
-                    hspec, spaces, pred_cache=cache, use_pallas=up,
-                )
             setup_bytes, per_round = self._fused_comm_model(state, setup_tree=spaces)
             self._account_comm(setup_bytes)
         else:
@@ -518,22 +485,16 @@ class Federation:
                     block_s=opt.tree_block_s, block_d=opt.tree_block_d,
                 )
             )
-            if traced:
-                stages = hetero.HETERO_ROUND_STAGES[self.plan.algorithm](
-                    hspec, use_pallas=up, batched_fit=opt.batched_fit,
-                    block_s=opt.tree_block_s, block_d=opt.tree_block_d,
-                )
             _, per_round = self._fused_comm_model(state)
-        staged = [(n, jax.jit(f)) for n, f in stages] if stages is not None else None
         if opt.cache_predictions:
             tallies = hetero.init_hetero_tally(
                 hspec, self.X_test.shape[0], committee=committee
             )
-            tally_fn = jax.jit(
+            tally_fn = jax.jit(jax.named_scope("eval")(
                 lambda ens, tl: hetero.hetero_tally_new_votes(
                     hspec, ens, tl, self.X_test, committee=committee,
                 )
-            )
+            ))
 
             def evaluate(state):
                 nonlocal tallies
@@ -542,18 +503,18 @@ class Federation:
                 return f1_macro(self.y_test, pred, hspec.n_classes)
 
         else:
-            predict = jax.jit(
+            predict = jax.jit(jax.named_scope("eval")(
                 lambda ens, X: hetero.hetero_strong_predict(
                     hspec, ens, X, committee=committee
                 )
-            )
+            ))
 
             def evaluate(state):
                 pred = predict(state.ensemble, self.X_test)
                 return f1_macro(self.y_test, pred, hspec.n_classes)
 
         return self._fused_loop(
-            rounds, eval_every, state, Xs, ys, masks, round_fn, staged,
+            rounds, eval_every, state, Xs, ys, masks, round_fn,
             evaluate, per_round, publish_every, publish_dir, on_checkpoint,
         )
 

@@ -116,6 +116,10 @@ def sharded_adaboost_round(
     ``weighted_errors`` reduction over it (then ``psum`` across the
     federation axes), and the chosen hypothesis's mispredictions are a
     row slice of the same matrix — never a second predict.
+
+    The steps run under ``jax.named_scope`` — ``fit``, ``exchange`` (the
+    all-gather and both ``psum``s), ``score`` and ``aggregate`` — so a
+    profiler trace attributes each chip's device time to them.
     """
     axes = fl_axes(mesh)
     has_cache = state.fit_cache is not None and learner.fit_cached is not None
@@ -129,44 +133,52 @@ def sharded_adaboost_round(
         kfit = jax.random.fold_in(key, idx)
 
         # paper step 2: local training + hypothesis-space broadcast
-        w_fit = wi / jnp.maximum(jnp.sum(wi), 1e-30) * jnp.maximum(jnp.sum(mi), 1.0)
-        if has_cache:  # shard-static precomputation (binning etc.)
-            cache_i = jax.tree.map(lambda x: x[0], cache_l[0])
-            h_local = learner.fit_cached(spec, None, Xi, yi, w_fit, kfit, cache_i)
-        else:
-            h_local = learner.fit(spec, None, Xi, yi, w_fit, kfit)
-        if packed_broadcast:  # one collective for the whole hypothesis
-            buf, fmt = _pack_leaves(h_local)
-            gathered = _multi_gather(buf, axes)  # [C, total]
-            hyps = _unpack_leaves(gathered, fmt, lead=(gathered.shape[0],))
-        else:  # per-leaf all-gathers (pre-optimisation OpenFL behaviour)
-            hyps = jax.tree.map(lambda l: _multi_gather(l, axes), h_local)
+        with jax.named_scope("fit"):
+            w_fit = wi / jnp.maximum(jnp.sum(wi), 1e-30) * jnp.maximum(jnp.sum(mi), 1.0)
+            if has_cache:  # shard-static precomputation (binning etc.)
+                cache_i = jax.tree.map(lambda x: x[0], cache_l[0])
+                h_local = learner.fit_cached(spec, None, Xi, yi, w_fit, kfit, cache_i)
+            else:
+                h_local = learner.fit(spec, None, Xi, yi, w_fit, kfit)
+        with jax.named_scope("exchange"):
+            if packed_broadcast:  # one collective for the whole hypothesis
+                buf, fmt = _pack_leaves(h_local)
+                gathered = _multi_gather(buf, axes)  # [C, total]
+                hyps = _unpack_leaves(gathered, fmt, lead=(gathered.shape[0],))
+            else:  # per-leaf all-gathers (pre-optimisation OpenFL behaviour)
+                hyps = jax.tree.map(lambda l: _multi_gather(l, axes), h_local)
         # hyps: [C, ...] — every collaborator now holds the full space
 
         # paper step 3: score the whole space on the local shard — predict
         # ONCE, then reduce with the kernel-backed weighted-error sum
-        preds = scoring.predict_matrix(learner, spec, hyps, Xi)  # [C, n]
-        local_errs = scoring.shard_errors(preds, yi, wi * mi, use_pallas=use_pallas)
-        eps = _multi_psum(local_errs, axes)  # weights globally normalised
+        with jax.named_scope("score"):
+            preds = scoring.predict_matrix(learner, spec, hyps, Xi)  # [C, n]
+            local_errs = scoring.shard_errors(preds, yi, wi * mi, use_pallas=use_pallas)
+        with jax.named_scope("exchange"):
+            eps = _multi_psum(local_errs, axes)  # weights globally normalised
 
         # paper step 4 (aggregator, replicated): select + alpha + append
-        c = jnp.argmin(eps)
-        alpha = _samme_alpha(eps[c], spec.n_classes)
-        chosen = _take_slot(hyps, c)
-        ens_params = _set_slot(ens_params, ens_count, chosen)
-        ens_alpha = ens_alpha.at[ens_count].set(alpha)
-        ens_count = ens_count + 1
+        with jax.named_scope("aggregate"):
+            c = jnp.argmin(eps)
+            alpha = _samme_alpha(eps[c], spec.n_classes)
+            chosen = _take_slot(hyps, c)
+            ens_params = _set_slot(ens_params, ens_count, chosen)
+            ens_alpha = ens_alpha.at[ens_count].set(alpha)
+            ens_count = ens_count + 1
 
-        # weight update + global renormalisation (the 'norm exchange');
-        # the chosen hypothesis's mispredictions are a row slice of preds
-        mis = scoring.chosen_mis(preds, yi, c)
-        wi = scoring.update_weights(
-            wi, mis, mi, alpha, use_pallas=use_pallas,
-            renormalize=False,  # renorm needs the cross-shard psum'd total below
-        )
-        total = _multi_psum(jnp.sum(wi), axes)
-        wi = wi / jnp.maximum(total, 1e-30)
-        metrics = {"epsilon": eps[c], "alpha": alpha, "chosen": c.astype(jnp.int32)}
+            # weight update + global renormalisation (the 'norm exchange');
+            # the chosen hypothesis's mispredictions are a row slice of preds
+            mis = scoring.chosen_mis(preds, yi, c)
+            wi = scoring.update_weights(
+                wi, mis, mi, alpha, use_pallas=use_pallas,
+                renormalize=False,  # renorm needs the cross-shard psum'd total below
+            )
+            local_total = jnp.sum(wi)
+        with jax.named_scope("exchange"):
+            total = _multi_psum(local_total, axes)
+        with jax.named_scope("aggregate"):
+            wi = wi / jnp.maximum(total, 1e-30)
+            metrics = {"epsilon": eps[c], "alpha": alpha, "chosen": c.astype(jnp.int32)}
         return ens_params, ens_alpha, ens_count, wi[None], metrics
 
     coll = P(axes) if axes else P()

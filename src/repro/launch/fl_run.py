@@ -69,10 +69,14 @@ def main(argv=None):
                          "shapes)")
     ap.add_argument("--sharded", action="store_true")
     ap.add_argument("--trace", default=None, metavar="PATH",
-                    help="record per-round phase spans (fit/score/aggregate/"
-                         "eval/publish) and write a Chrome-trace JSON loadable "
-                         "in Perfetto or chrome://tracing; also prints a "
-                         "phase-time summary table")
+                    help="record host spans (round, round.eval, "
+                         "round.publish; round.fit/score/aggregate only "
+                         "under --distributed, where they are host phases) "
+                         "and write a Chrome-trace JSON loadable in Perfetto "
+                         "or chrome://tracing; also prints a phase-time "
+                         "summary table. The spans also land on a JAX "
+                         "profiler capture, beside the fused round's "
+                         "fit/hist/score/aggregate/eval named scopes")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="dump the process metrics registry (counters/gauges/"
                          "histograms) in Prometheus text exposition format")

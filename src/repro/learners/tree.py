@@ -68,16 +68,19 @@ def _histogram_stage(
 ):
     """Level histogram via the kernel dispatch.  Accepts single-fit
     ([n, d]) or batched ([C, n, d]) inputs — batched inputs run as ONE
-    kernel launch (the batch axis folds into the Pallas grid)."""
+    kernel launch (the batch axis folds into the Pallas grid).  Runs
+    under ``jax.named_scope("hist")``: every fit path builds its level
+    histograms here, so a profiler trace names their device time."""
     kw = {}
     if block_s is not None:
         kw["block_s"] = block_s
     if block_d is not None:
         kw["block_d"] = block_d
-    return ops.tree_hist(
-        bin_idx, leaf, wy, n_leaves=n_leaves, n_bins_p1=n_bins + 1,
-        use_pallas=use_pallas, **kw,
-    )
+    with jax.named_scope("hist"):
+        return ops.tree_hist(
+            bin_idx, leaf, wy, n_leaves=n_leaves, n_bins_p1=n_bins + 1,
+            use_pallas=use_pallas, **kw,
+        )
 
 
 def _split_scores(C: jax.Array) -> jax.Array:

@@ -15,6 +15,14 @@ whose ``traceEvents`` are complete ("ph": "X") events with microsecond
 ``ts``/``dur`` — which both Perfetto (ui.perfetto.dev) and
 ``chrome://tracing`` load directly.
 
+**On the profiler's clock.**  While it is open, an enabled span also
+holds a ``jax.profiler.TraceAnnotation`` of its name, so a JAX profiler
+capture shows it on the host plane beside the device's operations —
+idle gaps on the device can be read against the host phase that left
+them.  Work inside a jitted program is named by ``jax.named_scope``
+instead (``fit`` / ``hist`` / ``score`` / ``aggregate`` / ``exchange``
+/ ``eval``): it appears in the ops' metadata, not as a span.
+
 **Disabled is free.**  The default tracer starts disabled and
 ``span()`` then returns one shared module-level no-op context manager —
 no object allocation, no clock read, no lock (tested by object identity
@@ -29,6 +37,8 @@ import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class _NoopSpan:
@@ -50,7 +60,7 @@ NOOP_SPAN = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "attrs", "_t0", "_id", "_parent")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "_id", "_parent", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self._tracer = tracer
@@ -68,11 +78,14 @@ class _Span:
         self._parent = stack[-1] if stack else None
         self._id = tr._next_id()
         stack.append(self._id)
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
         t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
         tr = self._tracer
         stack = tr._stack()
         if stack and stack[-1] == self._id:

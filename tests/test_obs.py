@@ -1,5 +1,5 @@
 """Observability layer: span tracer, metrics registry, and their
-integration with the federation's staged fused round."""
+integration with the federation's fused round."""
 import json
 import threading
 import tracemalloc
@@ -298,7 +298,7 @@ def test_registry_dump_and_reset(tmp_path):
     assert reg.counter("mafl_things_total") is c
 
 
-# -- integration: staged round + federation history --------------------------
+# -- integration: fused round + federation history ---------------------------
 
 @pytest.fixture(scope="module")
 def tiny():
@@ -348,20 +348,29 @@ def test_staged_round_equals_fused_round(tiny):
             )
 
 
-def test_traced_federation_emits_phase_spans_and_history_extras(tiny):
+def test_traced_federation_emits_phase_spans_and_history_extras(tiny, tmp_path):
+    """Tracing adds host spans and changes nothing else: a traced and an
+    untraced run of one seed end in the same bits, because both call the
+    one jitted round (fit / score / aggregate are named scopes inside
+    it, not host phases)."""
     from repro.core.plan import adaboost_plan
     from repro.fl.federation import Federation
 
     Xs, ys, masks, Xte, yte, lspec, key = tiny
-    trace.enable()
-    trace.reset()
-    try:
-        fed = Federation(
-            adaboost_plan(rounds=4), Xs, ys, masks, Xte, yte, lspec, key
-        )
-        hist = fed.run(eval_every=2)
-    finally:
-        trace.disable()
+
+    def run(traced: bool, out: str):
+        fed = Federation(adaboost_plan(rounds=4), Xs, ys, masks, Xte, yte, lspec, key)
+        if traced:
+            trace.enable()
+            trace.reset()
+        try:
+            hist = fed.run(eval_every=2, publish_every=2, publish_dir=str(tmp_path / out))
+        finally:
+            trace.disable()
+        return fed, hist
+
+    fed_off, hist_off = run(False, "off")
+    fed, hist = run(True, "on")
     # satellite: history rows carry wall-clock and comm deltas
     for h in hist:
         assert h["round_seconds"] > 0
@@ -374,10 +383,55 @@ def test_traced_federation_emits_phase_spans_and_history_extras(tiny):
     kid_names = {
         e["name"] for e in evs if e["args"].get("parent_id") in rounds
     }
-    # the tentpole decomposition: every phase is a child of a round span
-    assert {"round.fit", "round.score", "round.aggregate",
-            "round.eval"} <= kid_names
+    # the fused path's host spans: eval and publish inside each round
+    assert kid_names == {"round.eval", "round.publish"}
+    assert {e["name"] for e in evs} == {"round", "round.eval", "round.publish"}
     trace.reset()
+
+    # the traced path runs the same program: bit-identical state and history
+    for a, b in zip(jax.tree.leaves(fed._fused_state), jax.tree.leaves(fed_off._fused_state)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert len(hist) == len(hist_off)
+    for h, h_off in zip(hist, hist_off):
+        assert h.keys() == h_off.keys()
+        for k in h:
+            if k != "round_seconds":  # host wall clock
+                assert h[k] == h_off[k], (k, h[k], h_off[k])
+
+
+def test_enabled_span_lands_on_the_profiler_host_plane(tmp_path):
+    """An enabled span is also a ``jax.profiler.TraceAnnotation``: a JAX
+    profiler capture shows it, under its name, on the host plane — the
+    clock the device's operations are on."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    tr.enable()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with tr.span("round.publish", round=7):
+            jax.block_until_ready(jax.numpy.ones(8) * 2)
+        with Tracer().span("round.never"):  # disabled: a no-op, not an annotation
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"), recursive=True)
+    host = {
+        ev.name: ev
+        for plane in ProfileData.from_file(path).planes if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events
+    }
+    assert "round.publish" in host
+    assert "round.never" not in host
+    (e,) = tr.events()  # still in the span buffer for the Chrome-trace export
+    assert e["name"] == "round.publish" and e["args"]["round"] == 7
+    assert host["round.publish"].duration_ns > 0
 
 
 def test_untraced_federation_records_nothing(tiny):
