@@ -8,7 +8,9 @@ with 16 bins.  Data comes from ``--seed`` through ``data/synthetic.py``.
   python chip_smoke.py --four-chips  # four chips: the sharded round only
 
 One chip: ``Federation.run`` for a few rounds, built as ``fl_run`` builds
-it, once on the jnp oracle path and once with the Pallas kernels; the
+it, once with the jnp scoring oracles and once with the Pallas scoring
+kernels (the histogram runs its MXU kernel in both: the TPU always
+takes it, and ``tree_hist_error`` holds it to the ref.py oracle); the
 two runs must agree under the cross-path contract
 (``core/scoring.CROSS_PATH_RTOL``: the same chosen hypothesis every
 round, alphas within that relative bound) and on final macro-F1 within
@@ -200,7 +202,7 @@ def tree_hist_error(data, level: int = DEPTH - 1):
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.kernels import ops
+    from repro.kernels import ops, ref
     from repro.learners import get_learner
     from repro.learners.base import weighted_onehot
 
@@ -212,14 +214,11 @@ def tree_hist_error(data, level: int = DEPTH - 1):
     wy = jax.vmap(lambda yi, wi: weighted_onehot(yi, wi, lspec.n_classes))(data["ys"], w)
     n_leaves = 2**level
     leaf = jax.random.randint(jax.random.PRNGKey(level), w.shape, 0, n_leaves)
-    hist = jax.jit(
-        lambda b, l, v, up: ops.tree_hist(
-            b, l, v, n_leaves=n_leaves, n_bins_p1=N_BINS + 1, use_pallas=up
-        ),
-        static_argnums=3,
-    )
-    got = np.asarray(hist(cache.bin_idx, leaf, wy, True))
-    want = np.asarray(hist(cache.bin_idx, leaf, wy, False))
+    kernel = jax.jit(lambda b, l, v: ops.tree_hist(b, l, v, n_leaves=n_leaves,
+                                                    n_bins_p1=N_BINS + 1))
+    oracle = jax.jit(lambda b, l, v: ref.tree_hist_batched_ref(b, l, v, n_leaves, N_BINS + 1))
+    got = np.asarray(kernel(cache.bin_idx, leaf, wy))
+    want = np.asarray(oracle(cache.bin_idx, leaf, wy))
     err = float(np.max(np.abs(got - want)))
     scale = float(np.max(np.abs(want)))
     if not err <= 1e-5 * scale:

@@ -51,11 +51,12 @@ class OptimizationFlags:
     fused_round: jit the whole federated round as one program
         (removes per-task dispatch overhead; beyond-paper).
     use_pallas: route the step-3/4 scoring reductions (error matrix,
-        fused weight update) — and, with ``batched_fit``, the step-2
-        tree-fit histogram stage (``kernels/tree_hist.py``) — through
-        the Pallas TPU kernels instead of the pure-jnp oracles
-        (beyond-paper; off-TPU backends run the kernels in interpret
-        mode, so the default is off — flip on for TPU deployments).
+        fused weight update) through the Pallas TPU kernels instead of
+        the pure-jnp oracles (beyond-paper; off-TPU backends run the
+        kernels in interpret mode, so the default is off).  The step-2
+        tree-fit histogram (``kernels/tree_hist.py``) no longer waits
+        for it: on the TPU every fit path runs the MXU kernel; off the
+        TPU the flag runs that kernel interpreted, else its oracle.
     cache_predictions: predict-once caching (beyond-paper) —
         (a) PreWeak.F keeps a setup-time ``[C, C*T, n]`` prediction
         cache of its static hypothesis space, turning every round into
@@ -66,7 +67,7 @@ class OptimizationFlags:
         fused round trains all C weak hypotheses as ONE tensor program
         via ``WeakLearner.fit_batched`` over the shard-static
         ``BinnedDataset`` fit cache, instead of a vmap of C independent
-        fits; with ``use_pallas`` the per-level histogram is a single
+        fits; on the TPU the per-level histogram is a single
         ``tree_hist`` kernel launch whose grid folds the batch axis.
     tree_block_s / tree_block_d: sample/feature tile sizes of the
         ``tree_hist`` kernel; ``None`` (the default) derives them from

@@ -1,6 +1,11 @@
 """Public jit'd entry points for the Pallas kernels.
 
-Each op dispatches kernel vs. pure-jnp oracle:
+``tree_hist`` dispatches by backend: on the TPU it always runs the MXU
+kernel; elsewhere ``use_pallas=True`` runs the kernel in interpret mode
+and ``use_pallas=False`` the ref.py oracle, which stays the correctness
+ground truth of the CPU tests.
+
+The other ops dispatch kernel vs. pure-jnp oracle on ``use_pallas``:
   * ``use_pallas=True``  — the Pallas kernel; on CPU backends it runs in
     interpret mode (the TPU lowering is the deployment target);
   * ``use_pallas=False`` — the ref.py oracle (used by the dry-run so the
@@ -16,21 +21,41 @@ from repro.kernels.boost_update import weighted_errors as _weighted_errors
 from repro.kernels.flash_attention import flash_attention as _flash_attention
 from repro.kernels.tree_hist import tree_hist as _tree_hist
 from repro.kernels.vote_argmax import vote_argmax as _vote_argmax
+from repro.obs import metrics as obs_metrics
+
+
+# incremented while tracing: counts compiled programs, not launches
+_M_DISPATCH = obs_metrics.counter(
+    "mafl_kernel_dispatch_total",
+    "Kernel-or-oracle choices made while tracing, by kernel and path.",
+    labels=("kernel", "path"),
+)
+
+
+def _backend() -> str:
+    return jax.default_backend()
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return _backend() != "tpu"
 
 
 def tree_hist(bin_idx, leaf, wy, *, n_leaves, n_bins_p1, use_pallas=False, **kw):
     """Weighted class histogram; accepts [n, d] inputs or a leading
     hypothesis/collaborator batch axis ([H, n, d] — one kernel launch
-    for all H fits).  This is the fit-path hot-spot dispatch: the fused
-    round routes it under ``OptimizationFlags.use_pallas``."""
-    if use_pallas:
+    for all H fits).  This is the fit-path hot-spot dispatch, reached by
+    every fit path: the MXU kernel on the TPU whatever ``use_pallas``
+    says; elsewhere the kernel in interpret mode under ``use_pallas``,
+    else the oracle."""
+    if _backend() == "tpu":
+        path = "mxu"
+    else:
+        path = "interpret" if use_pallas else "oracle"
+    _M_DISPATCH.labels(kernel="tree_hist", path=path).inc()
+    if path != "oracle":
         return _tree_hist(
             bin_idx, leaf, wy, n_leaves=n_leaves, n_bins_p1=n_bins_p1,
-            interpret=_interpret(), **kw,
+            interpret=path == "interpret", **kw,
         )
     if bin_idx.ndim == 3:
         return ref.tree_hist_batched_ref(bin_idx, leaf, wy, n_leaves, n_bins_p1)

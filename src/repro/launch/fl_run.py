@@ -63,10 +63,11 @@ def main(argv=None):
     ap.add_argument("--eval-every", type=int, default=5)
     ap.add_argument("--faithful", action="store_true")
     ap.add_argument("--use-pallas", action="store_true",
-                    help="route the tree-fit histogram and the step-3/4 "
-                         "scoring through the Pallas kernels: compiled on a "
-                         "TPU, interpret mode on the CPU (slow at large "
-                         "shapes)")
+                    help="route the step-3/4 scoring through the Pallas "
+                         "kernels: compiled on a TPU, interpret mode on the "
+                         "CPU (slow at large shapes); off the TPU it also "
+                         "runs the tree-fit histogram kernel interpreted, "
+                         "which the TPU runs on every fit path anyway")
     ap.add_argument("--sharded", action="store_true")
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="record host spans (round, round.eval, "

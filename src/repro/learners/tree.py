@@ -11,8 +11,9 @@ The fit is a staged pipeline with a precomputable data layer:
              the digitized bin indices as the fit cache;
   histogram  each level accumulates a weighted class histogram
              C[leaf, feature, bin, class] — the compute hot-spot, routed
-             through ``kernels/ops.py::tree_hist`` (Pallas MXU kernel
-             under ``use_pallas``; segment-sum oracle otherwise);
+             through ``kernels/ops.py::tree_hist`` (the Pallas MXU kernel
+             on the TPU in every fit path; elsewhere the segment-sum
+             oracle, or the kernel interpreted under ``use_pallas``);
   select     split scores for every (feature, bin) candidate come from a
              reverse cumulative sum over the bin axis (split at bin b ==
              "x > edges[b]"); the best candidate maximises
@@ -66,9 +67,11 @@ def _histogram_stage(
     bin_idx, leaf, wy, n_leaves: int, n_bins: int,
     *, use_pallas: bool = False, block_s: int | None = None, block_d: int | None = None,
 ):
-    """Level histogram via the kernel dispatch.  Accepts single-fit
-    ([n, d]) or batched ([C, n, d]) inputs — batched inputs run as ONE
-    kernel launch (the batch axis folds into the Pallas grid).  Runs
+    """Level histogram via the kernel dispatch: the MXU kernel on the
+    TPU (whatever ``use_pallas`` says), the oracle or the interpreted
+    kernel elsewhere.  Accepts single-fit ([n, d]) or batched
+    ([C, n, d]) inputs — batched inputs run as ONE kernel launch (the
+    batch axis folds into the Pallas grid).  Runs
     under ``jax.named_scope("hist")``: every fit path builds its level
     histograms here, so a profiler trace names their device time."""
     kw = {}
@@ -208,13 +211,14 @@ def fit_tree_batched(
     block_d: int | None = None,
 ) -> TreeParams:
     """Fit all C collaborators' trees as ONE tensor program: per level,
-    one (optionally Pallas) ``tree_hist`` launch builds every
-    collaborator's histogram, and the select/descend/leaf stages vmap.
+    one ``tree_hist`` call builds every collaborator's histogram (one
+    kernel launch on the TPU), and the select/descend/leaf stages vmap.
 
-    With ``use_pallas=False`` this is bit-for-bit ``vmap(fit_tree)`` —
-    the histogram oracle is the per-slice oracle vmapped, and every
-    other stage is literally the single-fit stage under ``jax.vmap``
-    (regression-tested in tests/test_binning.py).
+    On the oracle backends (``use_pallas=False`` off the TPU) this is
+    bit-for-bit ``vmap(fit_tree)`` — the histogram oracle is the
+    per-slice oracle vmapped, and every other stage is literally the
+    single-fit stage under ``jax.vmap`` (regression-tested in
+    tests/test_binning.py).
     """
     depth = spec.hp("depth", 4)
     n_bins = spec.hp("n_bins", 16)

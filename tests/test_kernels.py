@@ -5,10 +5,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ref
+from repro.kernels import ops, ref
 from repro.kernels.boost_update import weight_update, weighted_errors
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.tree_hist import tree_hist
+from repro.kernels import tree_hist as th
+from repro.kernels.tree_hist import split3, tree_hist
+from repro.obs import metrics as obs_metrics
 
 
 @pytest.mark.parametrize("n,d,L,B1,K", [
@@ -93,6 +95,132 @@ def test_tree_hist_batched_kernel_matches_singles():
         np.testing.assert_allclose(
             np.asarray(batched[c]), np.asarray(single), atol=1e-5
         )
+
+
+def _six_decade_inputs(key, H, n, d, L, B1, K, n_zero):
+    """Bins, leaves and weighted one-hot labels whose weights spread over
+    six decades (so every bf16 piece of a label carries bits), with a
+    zero-weight tail of ``n_zero`` rows per slice."""
+    k1, k2, k3, k4, k5 = jax.random.split(key, 5)
+    bin_idx = jax.random.randint(k1, (H, n, d), 0, B1)
+    leaf = jax.random.randint(k2, (H, n), 0, L)
+    y = jax.random.randint(k3, (H, n), 0, K)
+    w = jax.random.uniform(k4, (H, n), minval=0.5, maxval=1.0)
+    w = w * 10.0 ** jax.random.uniform(k5, (H, n), minval=-6.0, maxval=0.0)
+    w = w * (jnp.arange(n) < n - n_zero)
+    return bin_idx, leaf, jax.nn.one_hot(y, K) * w[..., None]
+
+
+def _bucket_gap(got, want, bin_idx, leaf, wy, L, B1):
+    """Largest |got - want| of a bucket over the bucket's sum of |wy|."""
+    scale = np.asarray(ref.tree_hist_batched_ref(bin_idx, leaf, jnp.abs(wy), L, B1))
+    gap = np.abs(np.asarray(got, np.float64) - np.asarray(want, np.float64))
+    return float(np.max(gap / np.maximum(scale, 1e-30)))
+
+
+@pytest.mark.parametrize("K", [2, 26])
+@pytest.mark.parametrize("L", [1, 2, 4, 8, 32, 128])
+def test_tree_hist_levels_hold_float32(L, K):
+    """Every level's M = L x 17 buckets at K = 2 and K = 26 (a depth-4
+    tree's levels, and deep levels whose leaves take several leaf
+    blocks), n ragged against the tile, zero-weight tail rows: each
+    bucket within 1e-5 of the oracle relative to its sum of |wy|.  Labels
+    rounded once to bf16 miss by about 4e-3, so this tells the
+    three-piece split apart."""
+    H, n, d, B1 = 2, 300, 6, 17
+    bin_idx, leaf, wy = _six_decade_inputs(
+        jax.random.PRNGKey(10 + L + K), H, n, d, L, B1, K, n_zero=29
+    )
+    got = tree_hist(bin_idx, leaf, wy, n_leaves=L, n_bins_p1=B1,
+                    block_s=128, interpret=True)
+    want = ref.tree_hist_batched_ref(bin_idx, leaf, wy, L, B1)
+    assert got.shape == (H, L, d, B1, K)
+    assert _bucket_gap(got, want, bin_idx, leaf, wy, L, B1) < 1e-5
+    rounded = ref.tree_hist_batched_ref(
+        bin_idx, leaf, wy.astype(jnp.bfloat16).astype(jnp.float32), L, B1
+    )
+    assert _bucket_gap(rounded, want, bin_idx, leaf, wy, L, B1) > 1e-4
+
+
+@pytest.mark.parametrize("n,d,K", [
+    (4071, 14, 2),    # adult over 8
+    (7262, 54, 2),    # covtype over 64
+    (116202, 54, 2),  # covtype over 4, one shard a chip
+    (2000, 16, 26),   # letter over 8
+])
+def test_tree_hist_tiles_fit_scoped_vmem(n, d, K):
+    """At every level of a depth-8 tree the derived tiles keep the
+    kernel's VMEM (one-hot in f32 and bf16, labels spread to their leaves
+    in f32 and bf16, the output block double-buffered plus the dot's
+    result, the double-buffered inputs) well inside v5e's 16 MiB scoped
+    limit, on TPU-legal block shapes.  The compile for a described chip
+    does not check VMEM, so this is the guard."""
+    B1 = 17
+    J = th._label_rows(K)
+    for L in (2 ** lv for lv in range(8)):
+        bs, bd, bl = th.tiles(n, d, K, L, B1)
+        assert bs % 128 == 0 and bd % 16 == 0
+        assert bl == L or (bl % 8 == 0 and bl < L)
+        rows, cols = B1 * bd, -(-bl * J // 128) * 128
+        vmem = (B1 * bd * bs * (4 + 2) + bl * J * bs * (4 + 2)
+                + 3 * rows * cols * 4 + 2 * (bd + 8 + J) * bs * 4)
+        assert vmem < 12 * 2 ** 20, (L, bs, bd, bl, vmem)
+
+
+def test_tree_hist_split3_is_exact():
+    """hi + mid + lo == wy bit for bit, and every piece is a bf16 value."""
+    x = jax.random.uniform(jax.random.PRNGKey(11), (4096, 3), minval=-1.0, maxval=1.0)
+    x = x * 10.0 ** jax.random.uniform(jax.random.PRNGKey(12), (4096, 3), minval=-30, maxval=30)
+    p = split3(x)
+    assert bool(jnp.all(p[:, :3] + p[:, 3:6] + p[:, 6:] == x))
+    assert bool(jnp.all(p.astype(jnp.bfloat16).astype(jnp.float32) == p))
+
+
+def _dispatches(path):
+    family = obs_metrics.REGISTRY.get("mafl_kernel_dispatch_total")
+    for child in family.children() if family else ():
+        if dict(child.labels) == {"kernel": "tree_hist", "path": path}:
+            return child.value
+    return 0.0
+
+
+@pytest.mark.parametrize("backend,use_pallas,path", [
+    ("tpu", False, "mxu"),
+    ("tpu", True, "mxu"),
+    ("cpu", False, "oracle"),
+    ("cpu", True, "interpret"),
+])
+def test_tree_hist_dispatch_by_backend(monkeypatch, backend, use_pallas, path):
+    """The TPU takes the MXU kernel whatever ``use_pallas`` says; the CPU
+    takes the oracle unless ``use_pallas`` asks for the interpreted
+    kernel.  Each traced choice is counted under its path.  The other
+    kernels keep dispatching on ``use_pallas`` alone."""
+    calls = []
+
+    def spy(*a, **kw):
+        calls.append(kw["interpret"])
+        return jnp.zeros(())
+
+    monkeypatch.setattr(ops, "_backend", lambda: backend)
+    monkeypatch.setattr(ops, "_tree_hist", spy)
+    before = _dispatches(path)
+    bin_idx = jnp.zeros((4, 3), jnp.int32)
+    out = ops.tree_hist(bin_idx, jnp.zeros(4, jnp.int32), jnp.ones((4, 2)),
+                        n_leaves=1, n_bins_p1=5, use_pallas=use_pallas)
+    assert _dispatches(path) == before + 1
+    if path == "oracle":
+        assert calls == []
+        assert out.shape == (1, 3, 5, 2)
+    else:
+        assert calls == [path == "interpret"]
+
+    errs = []
+    monkeypatch.setattr(ops, "_weighted_errors", lambda *a, **kw: errs.append(kw))
+    preds = jnp.zeros((2, 4), jnp.int32)
+    ops.weighted_errors(preds, jnp.zeros(4, jnp.int32), jnp.ones(4), use_pallas=False)
+    assert errs == []
+    ops.weighted_errors(preds, jnp.zeros(4, jnp.int32), jnp.ones(4), use_pallas=True)
+    assert errs == [{"interpret": backend != "tpu"}]
 
 
 @pytest.mark.parametrize("H,n", [(3, 100), (8, 1000), (33, 4096)])

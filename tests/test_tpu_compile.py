@@ -1,6 +1,8 @@
 """Ahead-of-time compiles of the main-path Pallas kernels for a described
 TPU v5e (no chip needed): the TPU compiler refuses illegal block shapes
-and VMEM overruns that interpret mode never sees.  Each kernel is
+that interpret mode never sees.  It does not check a kernel's VMEM use
+(a block far past the chip's VMEM compiles here); the tiles' VMEM is
+held by ``tests/test_kernels.py``.  Each kernel is
 compiled through its public entry point at the widths the fused round
 and the serving engine use, and must lower to a Mosaic
 ``tpu_custom_call``.
@@ -45,9 +47,9 @@ def one_chip(topo):
 def compile_for(one_chip, monkeypatch):
     """``compile_for(fn, *shapes)`` -> compiled HLO text for the chip.
 
-    ``ops`` picks interpret mode from the default backend, which is the
-    CPU here; the kernels are steered to their TPU lowering instead."""
-    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    ``ops`` dispatches on the default backend, which is the CPU here;
+    the kernels are steered to their TPU dispatch and lowering instead."""
+    monkeypatch.setattr(ops, "_backend", lambda: "tpu")
 
     def run(fn, *shapes):
         args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
@@ -60,16 +62,29 @@ def _assert_kernel(text):
     assert 'custom_call_target="tpu_custom_call"' in text
 
 
-@pytest.mark.parametrize("C,n,d,K", [
-    (8, 4071, 14, 2),   # adult: 32,561 rows over 8 collaborators
-    (8, 2000, 16, 26),  # letter: 16,000 rows over 8 collaborators
-], ids=["adult", "letter"])
-def test_tree_hist_compiles(compile_for, C, n, d, K):
+@pytest.mark.parametrize("batch,n,d,K,leaves,flag", [
+    ((8,), 4071, 14, 2, LEAVES, {"use_pallas": True}),   # adult: 32,561 rows over 8 collaborators
+    ((8,), 2000, 16, 26, LEAVES, {"use_pallas": True}),  # letter: 16,000 rows over 8 collaborators
+    ((64,), 7262, 54, 2, LEAVES, {}),  # covtype_c64: 464,809 rows over 64, default dispatch
+    ((), 116202, 54, 2, LEAVES, {}),   # covtype_c4: one chip's 2-D shard, default dispatch
+    ((8,), 4071, 14, 2, 128, {}),  # adult at depth 8: its deepest level, default dispatch
+    ((8,), 2000, 16, 26, 32, {}),  # letter at depth 6: its deepest level, default dispatch
+], ids=["adult", "letter", "covtype_c64", "covtype_c4_shard", "adult_depth8", "letter_depth6"])
+def test_tree_hist_compiles(compile_for, batch, n, d, K, leaves, flag):
     text = compile_for(
-        lambda b, l, w: ops.tree_hist(
-            b, l, w, n_leaves=LEAVES, n_bins_p1=N_BINS_P1, use_pallas=True
-        ),
-        ((C, n, d), jnp.int32), ((C, n), jnp.int32), ((C, n, K), jnp.float32),
+        lambda b, l, w: ops.tree_hist(b, l, w, n_leaves=leaves, n_bins_p1=N_BINS_P1, **flag),
+        (batch + (n, d), jnp.int32), (batch + (n,), jnp.int32), (batch + (n, K), jnp.float32),
+    )
+    _assert_kernel(text)
+
+
+def test_tree_hist_tile_overrides_compile(compile_for):
+    """``tree_block_s`` / ``tree_block_d`` overrides off the lane and
+    sublane multiples are rounded up to them, so they still lower."""
+    text = compile_for(
+        lambda b, l, w: ops.tree_hist(b, l, w, n_leaves=LEAVES, n_bins_p1=N_BINS_P1,
+                                      block_s=200, block_d=4),
+        ((8, 4071, 14), jnp.int32), ((8, 4071), jnp.int32), ((8, 4071, 2), jnp.float32),
     )
     _assert_kernel(text)
 
