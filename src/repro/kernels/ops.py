@@ -19,6 +19,7 @@ from repro.kernels import ref
 from repro.kernels.boost_update import weight_update as _weight_update
 from repro.kernels.boost_update import weighted_errors as _weighted_errors
 from repro.kernels.flash_attention import flash_attention as _flash_attention
+from repro.kernels.tree_hist import tiles as tree_hist_tiles
 from repro.kernels.tree_hist import tree_hist as _tree_hist
 from repro.kernels.vote_argmax import vote_argmax as _vote_argmax
 from repro.obs import metrics as obs_metrics
@@ -29,6 +30,13 @@ _M_DISPATCH = obs_metrics.counter(
     "mafl_kernel_dispatch_total",
     "Kernel-or-oracle choices made while tracing, by kernel and path.",
     labels=("kernel", "path"),
+)
+# incremented while tracing: the one-hot rebuilds of one histogram call
+_M_LEAF_BLOCKS = obs_metrics.counter(
+    "mafl_kernel_tree_hist_leaf_blocks_total",
+    "tree_hist calls traced on the kernel paths, by the level's leaves and "
+    "the leaf blocks the kernel splits them into.",
+    labels=("n_leaves", "blocks"),
 )
 
 
@@ -53,6 +61,9 @@ def tree_hist(bin_idx, leaf, wy, *, n_leaves, n_bins_p1, use_pallas=False, **kw)
         path = "interpret" if use_pallas else "oracle"
     _M_DISPATCH.labels(kernel="tree_hist", path=path).inc()
     if path != "oracle":
+        n, d = bin_idx.shape[-2:]
+        *_, block_l = tree_hist_tiles(n, d, wy.shape[-1], n_leaves, n_bins_p1)
+        _M_LEAF_BLOCKS.labels(n_leaves=n_leaves, blocks=-(-n_leaves // block_l)).inc()
         return _tree_hist(
             bin_idx, leaf, wy, n_leaves=n_leaves, n_bins_p1=n_bins_p1,
             interpret=path == "interpret", **kw,

@@ -54,7 +54,6 @@ from repro.core.serialization import (
     encode_leaf,
     encoded_nbytes,
     outlier_rows,
-    serialize,
     wire_format,
 )
 from repro.learners import LearnerSpec, WeakLearner, available_learners, get_learner
@@ -308,15 +307,30 @@ def _quantized_payload(ensemble: Any, plans: list) -> bytes:
 def _maybe_quantize(
     spec, ensemble, quantize: Optional[str], calibrate, committee_size
 ):
-    """Returns (payload, leaf_codecs) — leaf_codecs is None unquantized."""
+    """Returns (payload, leaf_codecs): the payload as byte buffers whose
+    concatenation is the file's payload; leaf_codecs is None unquantized.
+
+    Unquantized, the buffers are the leaves themselves, fetched from the
+    device in one transfer and checksummed and written in place: the
+    bytes of ``serialize(ensemble, packed=True)`` without its joined copy
+    (a rolling checkpoint of a large capacity spends most of its time
+    copying)."""
     if quantize is None:
-        return serialize(ensemble, packed=True)[0], None
+        leaves = jax.tree.leaves(jax.device_get(ensemble))
+        return [np.ascontiguousarray(l).reshape(-1).view(np.uint8) for l in leaves], None
     ensembles = list(ensemble) if isinstance(spec, HeterogeneousSpec) else [ensemble]
     plans = _plan_ensembles(ensembles, quantize)
     if calibrate is not None:
         plans = _calibrate_plans(spec, ensemble, plans, calibrate, committee_size)
     plans = _demote_uneconomic(ensemble, plans)
-    return _quantized_payload(ensemble, plans), plans
+    return [_quantized_payload(ensemble, plans)], plans
+
+
+def _payload_fields(payload: list) -> dict:
+    crc = 0
+    for chunk in payload:
+        crc = zlib.crc32(chunk, crc)
+    return {"payload_bytes": sum(len(chunk) for chunk in payload), "payload_crc32": crc}
 
 
 def save_artifact(
@@ -370,8 +384,7 @@ def save_artifact(
         "ensemble_capacity": int(ensemble.alpha.shape[0]),
         "ensemble_count": int(ensemble.count),
         "committee_size": committee_size,
-        "payload_bytes": len(payload),
-        "payload_crc32": zlib.crc32(payload),
+        **_payload_fields(payload),
     }
     if plans is not None:
         manifest["quantize"] = quantize
@@ -379,7 +392,7 @@ def save_artifact(
     return _write(path, manifest, payload, extra)
 
 
-def _write(path: Path, manifest: dict, payload: bytes, extra: dict | None) -> Path:
+def _write(path: Path, manifest: dict, payload: list, extra: dict | None) -> Path:
     overlap = set(extra or {}) & set(manifest)
     if overlap:
         raise ValueError(f"extra manifest keys shadow required fields: {sorted(overlap)}")
@@ -390,7 +403,8 @@ def _write(path: Path, manifest: dict, payload: bytes, extra: dict | None) -> Pa
         f.write(MAGIC)
         f.write(struct.pack("<I", len(blob)))
         f.write(blob)
-        f.write(payload)
+        for chunk in payload:
+            f.write(chunk)
     return path
 
 
@@ -452,8 +466,7 @@ def _save_hetero(
         "ensemble_capacity": T,
         "ensemble_count": hetero.hetero_count(ensemble, committee=committee),
         "committee_size": committee_size,
-        "payload_bytes": len(payload),
-        "payload_crc32": zlib.crc32(payload),
+        **_payload_fields(payload),
     }
     if plans is not None:
         manifest["quantize"] = quantize
