@@ -40,6 +40,11 @@ _M_LEAF_BLOCKS = obs_metrics.counter(
 )
 
 
+def count_dispatch(kernel: str, path: str) -> None:
+    """Count one traced choice of ``path`` for ``kernel`` (call while tracing)."""
+    _M_DISPATCH.labels(kernel=kernel, path=path).inc()
+
+
 def _backend() -> str:
     return jax.default_backend()
 
@@ -59,7 +64,7 @@ def tree_hist(bin_idx, leaf, wy, *, n_leaves, n_bins_p1, use_pallas=False, **kw)
         path = "mxu"
     else:
         path = "interpret" if use_pallas else "oracle"
-    _M_DISPATCH.labels(kernel="tree_hist", path=path).inc()
+    count_dispatch("tree_hist", path)
     if path != "oracle":
         n, d = bin_idx.shape[-2:]
         *_, block_l = tree_hist_tiles(n, d, wy.shape[-1], n_leaves, n_bins_p1)

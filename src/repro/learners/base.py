@@ -58,6 +58,9 @@ satisfy:
                        its own ``fit_batched`` over its members, so a
                        kernel-backed learner keeps its one-launch fit
                        even when mixed with closed-form families.
+    ``predict_class`` — the class without the ``[n, K]`` logits (see the
+                       field comment below); ``predict`` uses it when
+                       set, ``argmax(predict_logits)`` otherwise.
 
 Registering a new learner
 -------------------------
@@ -159,8 +162,16 @@ class WeakLearner:
     #   when ``use_pallas=False`` (the kernel path agrees to float32
     #   tolerance and is parity-swept in tests/test_kernels.py).
     fit_batched: Callable[..., Params] | None = None
+    # Optional direct class prediction: ``predict_class(spec, params, X)
+    # -> [n] i32`` must satisfy  predict_class(...) ==
+    # argmax(predict_logits(...), -1)  bit-for-bit (first max on ties) —
+    # an optimisation, never a semantic knob.  The trees pick each row's
+    # class from a per-leaf class table instead of gathering K logits a row.
+    predict_class: Callable[[LearnerSpec, Params, jax.Array], jax.Array] | None = None
 
     def predict(self, spec: LearnerSpec, params: Params, X: jax.Array) -> jax.Array:
+        if self.predict_class is not None:
+            return self.predict_class(spec, params, X)
         return jnp.argmax(self.predict_logits(spec, params, X), axis=-1).astype(jnp.int32)
 
 

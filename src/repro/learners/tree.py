@@ -21,6 +21,10 @@ The fit is a staged pipeline with a precomputable data layer:
              to minimising weighted Gini impurity;
   leaf       leaf log-distributions from a weighted segment-sum.
 
+``predict`` (``tree_predict_class``) selects each row's class from a
+per-leaf class table; ``tree_predict_logits`` serves callers that want
+the scores.
+
 Every stage is expressed per-collaborator and vmaps cleanly;
 ``fit_tree_batched`` fuses the C collaborators of a federated round into
 ONE histogram launch per level (the kernel folds the batch axis into its
@@ -264,14 +268,34 @@ def init_tree(spec: LearnerSpec, key: jax.Array) -> TreeParams:
     )
 
 
-def tree_predict_logits(spec: LearnerSpec, params: TreeParams, X: jax.Array) -> jax.Array:
-    depth = params.feature.shape[0]
+def _leaf_index(params: TreeParams, X: jax.Array) -> jax.Array:
+    """Every row's leaf: [n] i32 in [0, 2**depth)."""
     leaf = jnp.zeros(X.shape[0], dtype=jnp.int32)
-    for level in range(depth):
+    for level in range(params.feature.shape[0]):
         f = params.feature[level]
         bit = X[:, f] > params.threshold[level]
         leaf = leaf * 2 + bit.astype(jnp.int32)
-    return params.leaf_logits[leaf]
+    return leaf
+
+
+def tree_predict_logits(spec: LearnerSpec, params: TreeParams, X: jax.Array) -> jax.Array:
+    return params.leaf_logits[_leaf_index(params, X)]
+
+
+def tree_predict_class(spec: LearnerSpec, params: TreeParams, X: jax.Array) -> jax.Array:
+    """``WeakLearner.predict_class``: bit for bit
+    ``argmax(tree_predict_logits(...), -1)``.  A leaf's argmax does not
+    depend on the row, so it is taken once per leaf into a ``[2**depth]``
+    class table, and each row picks its class with one elementwise select
+    per leaf: no per-row gather of K logits, no ``[n, K]`` buffer, and
+    under the scoring vmaps the selects fuse with the level compares."""
+    ops.count_dispatch("tree_predict", "leaf_select")
+    leaf = _leaf_index(params, X)
+    cls = jnp.argmax(params.leaf_logits, axis=-1).astype(jnp.int32)  # [2**depth]
+    out = jnp.broadcast_to(cls[0], leaf.shape)
+    for l in range(1, cls.shape[0]):
+        out = jnp.where(leaf == l, cls[l], out)
+    return out
 
 
 def tree_edges(spec: LearnerSpec, X: jax.Array) -> jax.Array:
@@ -296,7 +320,7 @@ decision_tree = register(
     WeakLearner(
         "decision_tree", init_tree, fit_tree, tree_predict_logits,
         precompute=tree_precompute, fit_cached=_fit_tree_cached,
-        fit_batched=fit_tree_batched,
+        fit_batched=fit_tree_batched, predict_class=tree_predict_class,
     )
 )
 
@@ -309,5 +333,6 @@ extra_tree = register(
         precompute=tree_precompute,
         fit_cached=functools.partial(_fit_tree_cached, random_splits=True),
         fit_batched=functools.partial(fit_tree_batched, random_splits=True),
+        predict_class=tree_predict_class,
     )
 )

@@ -176,10 +176,10 @@ def test_tree_hist_split3_is_exact():
     assert bool(jnp.all(p.astype(jnp.bfloat16).astype(jnp.float32) == p))
 
 
-def _dispatches(path):
+def _dispatches(path, kernel="tree_hist"):
     family = obs_metrics.REGISTRY.get("mafl_kernel_dispatch_total")
     for child in family.children() if family else ():
-        if dict(child.labels) == {"kernel": "tree_hist", "path": path}:
+        if dict(child.labels) == {"kernel": kernel, "path": path}:
             return child.value
     return 0.0
 
@@ -221,6 +221,30 @@ def test_tree_hist_dispatch_by_backend(monkeypatch, backend, use_pallas, path):
     assert errs == []
     ops.weighted_errors(preds, jnp.zeros(4, jnp.int32), jnp.ones(4), use_pallas=True)
     assert errs == [{"interpret": backend != "tpu"}]
+
+
+@pytest.mark.parametrize("name,counted", [
+    ("decision_tree", 1),
+    ("extra_tree", 1),
+    ("ridge", 0),
+    ("gaussian_nb", 0),
+])
+def test_tree_predict_leaf_select_dispatch_is_counted(name, counted):
+    """Tracing a tree's ``predict`` counts one ``tree_predict`` /
+    ``leaf_select`` choice, under vmap as well; a learner without
+    ``predict_class`` traces its logit argmax and counts nothing."""
+    from repro.learners import LearnerSpec, get_learner
+
+    learner = get_learner(name)
+    spec = LearnerSpec(name, 5, 3, {"depth": 4})
+    params = learner.init(spec, jax.random.PRNGKey(0))
+    X = jnp.zeros((7, 5))
+    before = _dispatches("leaf_select", kernel="tree_predict")
+    jax.make_jaxpr(lambda p, X: learner.predict(spec, p, X))(params, X)
+    assert _dispatches("leaf_select", kernel="tree_predict") == before + counted
+    stacked = jax.tree.map(lambda a: jnp.stack([a, a]), params)
+    jax.make_jaxpr(jax.vmap(lambda p: learner.predict(spec, p, X)))(stacked)
+    assert _dispatches("leaf_select", kernel="tree_predict") == before + 2 * counted
 
 
 @pytest.mark.parametrize("H,n", [(3, 100), (8, 1000), (33, 4096)])

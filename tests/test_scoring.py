@@ -4,6 +4,7 @@ incremental vote tally, and the no-double-predict regression."""
 import dataclasses
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -145,13 +146,19 @@ def test_round_predicts_once_per_hypothesis_space(vehicle):
     the predict path exactly once (vmap folds the H and C axes)."""
     learner, lspec, Xs, ys, masks, *_ = vehicle
     calls = {"n": 0}
-    base_logits = learner.predict_logits
 
-    def counting_logits(spec, params, X):
-        calls["n"] += 1
-        return base_logits(spec, params, X)
+    def counting(fn):
+        def wrapped(spec, params, X):
+            calls["n"] += 1
+            return fn(spec, params, X)
+        return wrapped
 
-    counted = dataclasses.replace(learner, predict_logits=counting_logits)
+    # the tree predicts through ``predict_class``; count either predict path
+    counted = dataclasses.replace(
+        learner,
+        predict_logits=counting(learner.predict_logits),
+        predict_class=learner.predict_class and counting(learner.predict_class),
+    )
     state = boosting.init_boost_state(counted, lspec, 2, masks, jax.random.PRNGKey(5))
     jax.make_jaxpr(
         lambda s: boosting.adaboost_f_round(counted, lspec, s, Xs, ys, masks)
@@ -170,3 +177,47 @@ def test_round_predicts_once_per_hypothesis_space(vehicle):
         )
     )(st)
     assert calls["n"] == 0, "cached PreWeak.F round must be a pure reduction"
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs included."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    yield from _eqns(sub.jaxpr)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("name", ["decision_tree", "extra_tree"])
+def test_tree_predict_tensor_has_no_per_row_logit_gather(name):
+    """The tree's scoring tensor picks each row's class from a per-leaf
+    class table: its program gathers no K leaf logits a row and holds no
+    ``[C, H, n, K]`` float buffer.  The column fetches ``X[:, f]`` stay.
+    The logit-argmax formulation of the same predictions has both, so
+    the check sees them when they come back."""
+    from repro.learners.tree import init_tree, tree_predict_logits
+
+    C, H, n, d, K = 3, 5, 40, 6, 3
+    spec = LearnerSpec(name, d, K, {"depth": 4})
+    learner = get_learner(name)
+    hyps = jax.vmap(lambda k: init_tree(spec, k))(jax.random.split(jax.random.PRNGKey(0), H))
+    X = jnp.zeros((C, n, d))
+
+    def logit_gathers(fn):
+        eqns = list(_eqns(jax.make_jaxpr(fn)(hyps, X).jaxpr))
+        gathers = [e for e in eqns if e.primitive.name == "gather"
+                   and e.params["slice_sizes"][-1] == K]
+        buffers = [v for e in eqns for v in e.outvars
+                   if v.aval.shape == (C, H, n, K) and v.aval.dtype == jnp.float32]
+        return gathers, buffers
+
+    assert logit_gathers(
+        lambda h, x: scoring.predict_tensor(learner, spec, h, x)
+    ) == ([], [])
+    oracle = jax.vmap(lambda Xi: jax.vmap(
+        lambda p: jnp.argmax(tree_predict_logits(spec, p, Xi), axis=-1))(hyps))
+    gathers, buffers = logit_gathers(lambda h, x: oracle(x))
+    assert gathers and buffers
